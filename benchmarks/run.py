@@ -119,7 +119,10 @@ def main(argv=None) -> int:
 
     if args.run_one:
         # child process: env (devices, threads) already committed by the
-        # parent; run exactly one config and write its result
+        # parent; run exactly one config and write its result. Only the
+        # child imports jax: the parent stays off the chip.
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
         with open(args.run_one) as f:
             config = experiments.ExperimentConfig.from_json(json.load(f))
         result = experiments.run_config_inprocess(config)

@@ -147,11 +147,17 @@ class ClusterConfig:
 
 
 def pick_devices(n: int) -> List[Optional[jax.Device]]:
-    """First ``n`` JAX devices, reusing the ladder round-robin (with a
-    warning) when fewer exist — on CPU, start the process with
+    """First ``n`` JAX devices. On CPU, fewer devices are reused
+    round-robin with a warning — start the process with
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` to simulate
-    N devices (see docs/cluster.md)."""
+    N devices (see docs/cluster.md). On an accelerator, fewer devices
+    than replicas raises: replicas sharing a chip would pass for a
+    fleet that does not exist."""
     devs = jax.devices()
+    if len(devs) < n and devs[0].platform != "cpu":
+        raise ValueError(
+            f"cluster wants {n} replicas but only {len(devs)} "
+            f"{devs[0].platform} device(s) exist")
     if len(devs) < n:
         warnings.warn(
             f"cluster wants {n} replicas but only {len(devs)} JAX "

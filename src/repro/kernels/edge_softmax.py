@@ -57,13 +57,13 @@ def _edge_softmax_kernel(q_ref, k_ref, r_ref, v_ref, o_ref,
 
     q = q_ref[...]                                  # (cap, Fp) node queries
     k = k_ref[...]                                  # (be, Fp) edge keys+bias
-    r = r_ref[...]                                  # (be,) local receiver idx
+    r = r_ref[...]                                  # (be, 1) local receiver idx
     cap = q.shape[0]
     be = k.shape[0]
 
     # one-hot receiver matrix: R[e, i] = 1 iff edge e scatters to node i
     iota = jax.lax.broadcasted_iota(jnp.int32, (be, cap), 1)
-    onehot = r[:, None] == iota                     # (be, cap) bool
+    onehot = r == iota                              # (be, cap) bool
     R = onehot.astype(jnp.float32)
 
     # gather receiver queries and take the fused logit row-sum (the last
@@ -105,7 +105,9 @@ def edge_softmax_kernel(q, k_e, recv_local, values, *, cap: int,
                 column constant 1 (bias pickup).
     k_e:        (B * ec, Fp) f32 — gathered sender keys; last column is
                 the attention bias, -1e9 on masked edge slots.
-    recv_local: (B * ec,) int32 — receiver index within the molecule.
+    recv_local: (B * ec, 1) int32 — receiver index within the molecule,
+                as a column: a 1-D int32 block does not match the TPU's
+                tiled layout for 1-D arrays, and Mosaic refuses it.
     values:     (B * ec, W) f32 — per-edge values, zero on masked slots.
 
     Returns (B * cap, W) f32: out[i] = sum_e alpha_e * values[e] over
@@ -128,7 +130,7 @@ def edge_softmax_kernel(q, k_e, recv_local, values, *, cap: int,
         in_specs=[
             pl.BlockSpec((cap, fp), lambda i, j: (i, 0)),
             pl.BlockSpec((be, fp), lambda i, j, n_eb=n_eb: (i * n_eb + j, 0)),
-            pl.BlockSpec((be,), lambda i, j, n_eb=n_eb: (i * n_eb + j,)),
+            pl.BlockSpec((be, 1), lambda i, j, n_eb=n_eb: (i * n_eb + j, 0)),
             pl.BlockSpec((be, w), lambda i, j, n_eb=n_eb: (i * n_eb + j, 0)),
         ],
         out_specs=pl.BlockSpec((cap, w), lambda i, j: (i, 0)),

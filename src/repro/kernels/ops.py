@@ -1,8 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Each wrapper handles: dynamic activation quantization, padding to block
-multiples, platform dispatch (interpret=True on CPU so the same code runs in
-this container; compiled path on TPU), and the packing/layout transforms.
+multiples, platform dispatch (the compiled kernels on TPU; the Pallas
+interpreter on the CPU backend, which is the test path), and the
+packing/layout transforms.
 """
 from __future__ import annotations
 
@@ -82,6 +83,10 @@ def matmul_w4a8(x: jnp.ndarray, w_packed: jnp.ndarray, w_scale: jnp.ndarray,
     m, k = x.shape
     n = w_packed.shape[1] * 2
     bm, bn, bk = block
+    if n > 128:
+        # a packed block narrower than the packed array must span whole
+        # 128-lane tiles on TPU: 128 bytes = 256 output columns
+        bn = max(bn, 256)
     a_q, a_scale = quantize_activations(x)
     a_q = _pad_to(_pad_to(a_q, 0, bm), 1, bk)
     a_scale = _pad_to(a_scale, 0, bm)
@@ -296,19 +301,25 @@ def _edge_softmax_pallas(q_scaled, k, bias, values, senders, receivers,
     """Layout prep + kernel launch. Folds the bias into the key's last
     column (queries get a constant-1 column), zeroes masked keys/values,
     localizes receiver indices, and pads feature dims to the 128-lane
-    contract before calling ``edge_softmax_kernel``."""
+    contract before calling ``edge_softmax_kernel``. Each molecule's
+    node rows are padded to a multiple of 8, the TPU's sublane tile: an
+    MD replica batch keeps the molecule's own atom count as its
+    capacity, and a node block of, say, 21 rows does not compile."""
     n, _ = q_scaled.shape
     w = values.shape[1]
+    b = n // cap
+    cap8 = -(-cap // 8) * 8
     qp = _pad_to(jnp.concatenate(
         [q_scaled, jnp.ones((n, 1), q_scaled.dtype)], axis=1), 1, 128)
+    qp = _pad_to(qp.reshape(b, cap, -1), 1, 8).reshape(b * cap8, -1)
     k_e = k[senders] * edge_mask[:, None]
     bias_m = jnp.where(edge_mask, bias, _NEG_BIAS)
     kp = _pad_to(jnp.concatenate([k_e, bias_m[:, None]], axis=1), 1, 128)
     vp = _pad_to(values * edge_mask[:, None], 1, 128)
-    recv_local = (receivers % cap).astype(jnp.int32)
-    out = _es.edge_softmax_kernel(qp, kp, recv_local, vp, cap=cap,
+    recv_local = (receivers % cap).astype(jnp.int32)[:, None]
+    out = _es.edge_softmax_kernel(qp, kp, recv_local, vp, cap=cap8,
                                   interpret=_interpret())
-    return out[:, :w]
+    return out.reshape(b, cap8, -1)[:, :cap, :w].reshape(n, w)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7,))

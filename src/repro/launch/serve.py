@@ -63,6 +63,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 # ---------------------------------------------------------------------------
 # LM decode workload (KV-cached token loop)
@@ -574,6 +576,7 @@ def main():
                          ".npz and continue")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cleanup_obs = _setup_obs(args)
     try:
         if args.workload == "lm":

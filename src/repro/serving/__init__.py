@@ -5,8 +5,8 @@ molecular graphs, buckets and pads them into MXU-aligned (multiple-of-128)
 shape classes to bound recompilation, runs the quantized SO3krates forward
 pass — dense O(n^2) oracle or sparse O(E) edge-list path with its fused
 segment-softmax kernel, selected per batch by ``ServeConfig.path`` —
-through the fused W8A8/W4A8 Pallas kernels (CPU ``interpret=True``
-fallback selected automatically when no TPU is present), and returns
+through the fused W8A8/W4A8 Pallas kernels (compiled on TPU,
+interpreted on the CPU backend for the tests), and returns
 per-molecule energies and conservative forces with padding masked out of
 both results and LEE diagnostics.
 

@@ -11,9 +11,8 @@ molecular graphs in, per-molecule energies/forces out, with
   dispatches each batch sparse whenever its cutoff graph fits the
   bucket's edge capacity (falling back to dense when it doesn't),
 * **real quantized weights** (``repro.serving.qparams``) streamed through
-  the fused W8A8/W4A8 Pallas kernels — ``interpret=True`` is selected
-  automatically when no TPU is present so the identical code path runs on
-  CPU,
+  the fused W8A8/W4A8 Pallas kernels — on the CPU backend they run
+  under the Pallas interpreter, the test path (``interpret``),
 * **masked batching**: padded atoms are excluded from results and
   diagnostics exactly, not approximately.
 

@@ -22,8 +22,8 @@ Quantization policy (mirrors ``repro.quant.apply`` for LMs, paper §III-D):
   error-amplifying leaf).
 
 ``qmatmul`` is the single entry point the serving forward pass uses: it
-dispatches on the stored kind, runs the Pallas kernel (interpret=True
-automatically on CPU), and carries a straight-through custom VJP so
+dispatches on the stored kind, runs the Pallas kernel (interpreted on the
+CPU backend), and carries a straight-through custom VJP so
 conservative forces ``F = -dE/dr`` can still be taken through the integer
 kernels — the backward pass multiplies by the *dequantized* weight matrix.
 """

@@ -24,6 +24,7 @@ only runs there).
 """
 import threading
 import time
+import types
 
 import jax
 import numpy as np
@@ -34,6 +35,7 @@ from repro.serving import Graph, QuantizedEngine, ServeConfig
 from repro.server import (SchedulerClosed, SchedulerOverloaded, load_engine,
                           save_artifact)
 from repro.cluster import ClusterConfig, ClusterPool
+from repro.cluster import pool as pool_mod
 
 CFG = so3.So3kratesConfig(feat=32, vec_feat=8, n_layers=2, n_rbf=8,
                           dir_bits=6, cutoff=3.0)
@@ -157,6 +159,21 @@ class TestRoutingIdentity:
             leaf = next(iter(rep.engine.qparams.values()))
             data = leaf.data if hasattr(leaf, "data") else leaf
             assert data.devices() == {rep.engine.device}
+
+    @pytest.mark.parametrize("platform", ["cpu", "tpu"])
+    def test_fewer_devices_than_replicas_shared_only_on_cpu(
+            self, monkeypatch, platform):
+        """CPU replicas share devices with a warning; on an accelerator
+        a shared chip would pass for a fleet that does not exist."""
+        fake = [types.SimpleNamespace(platform=platform) for _ in range(2)]
+        monkeypatch.setattr(pool_mod.jax, "devices", lambda: fake)
+        if platform == "cpu":
+            with pytest.warns(UserWarning, match="share devices"):
+                assert pool_mod.pick_devices(4) == fake * 2
+        else:
+            with pytest.raises(ValueError, match="only 2 tpu device"):
+                pool_mod.pick_devices(4)
+        assert pool_mod.pick_devices(2) == fake
 
 
 class TestBoundedAdmission:

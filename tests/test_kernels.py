@@ -105,7 +105,8 @@ def _edge_problem(seed, B, cap, ec, F, W, cutoff=3.0):
 class TestEdgeSoftmaxKernel:
     @pytest.mark.parametrize("B,cap,ec,F,W", [(2, 16, 256, 32, 56),
                                               (4, 32, 128, 64, 128),
-                                              (1, 128, 512, 16, 80)])
+                                              (1, 128, 512, 16, 80),
+                                              (2, 21, 512, 64, 112)])
     def test_matches_ref(self, B, cap, ec, F, W):
         q, k, bias, vals, s, r, m = _edge_problem(B, B, cap, ec, F, W)
         out = ops.edge_softmax(q, k, bias, vals, s, r, m, cap=cap,
