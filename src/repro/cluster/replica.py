@@ -64,7 +64,7 @@ from repro.guardrails import GuardrailViolation
 from repro.obs.metrics import REGISTRY
 from repro.serving.engine import QuantizedEngine
 from repro.server.scheduler import BatchQueue, RequestHandle, SchedulerConfig
-from repro.server.stats import FlushRecord
+from repro.server.stats import FlushClock, FlushRecord
 
 __all__ = ["ChunkHandle", "Replica", "ReplicaFailed"]
 
@@ -477,10 +477,11 @@ class Replica:
             self._last_beat = time.monotonic()
         self.ready.set()
 
+        clock = FlushClock()
         while True:
             in_flight: List[RequestHandle] = []
             chunk: Optional[ChunkHandle] = None
-            with self._lock:
+            with clock.wait(), self._lock:
                 while True:
                     now = time.monotonic()
                     if self._expropriated:
@@ -579,71 +580,77 @@ class Replica:
                     return
                 continue
             service_s = time.monotonic() - t0
-            # stamp the escalation audit trail the pool appended to each
-            # handle (and the obs trace id) into its delivered result
-            results = [dataclasses.replace(
-                           r, replica_id=self.replica_id,
-                           escalations=tuple(h.escalations),
-                           trace_id=(h.trace.trace_id
-                                     if h.trace is not None else ""))
-                       for h, r in zip(handles, results)]
-            trace_ids = tuple(h.trace.trace_id for h in handles
-                              if h.trace is not None)
-            # stub engines in tests may not expose the profiling hook
-            bd = getattr(engine, "last_infer_breakdown", None) or {}
-            with self._lock:
-                self._busy_since = None
-                self._in_flight = []
-                expropriated = self._expropriated
-                self._n_completed += len(handles)
-                self._consecutive_errors = 0
-                self._last_beat = time.monotonic()
-                self._flushes.append(FlushRecord(
-                    capacity=cap, n_requests=len(handles), reason=reason,
-                    queue_depth=depth, wait_s=wait_s, service_s=service_s,
-                    path=results[0].path, batch_size=results[0].batch_size,
-                    replica_id=self.replica_id, trace_ids=trace_ids,
-                    prep_s=bd.get("prep_s", 0.0),
-                    dispatch_s=bd.get("dispatch_s", 0.0),
-                    sync_s=bd.get("sync_s", 0.0),
-                    t_start=t0))
-                # feed the circuit-breaker window (flush results only —
-                # chunk health is the session layer's concern)
-                for r in results:
-                    self._recent_flags.append(bool(r.flags))
-                self._n_flagged += sum(1 for r in results if r.flags)
-            self._m_completed.inc(len(handles))
-            self._m_wait.observe(wait_s)
-            self._m_service.observe(service_s)
-            self._m_service_r.observe(service_s)
-            REGISTRY.counter("serve_flushes_total", surface="replica",
-                             reason=reason).inc()
-            for h, r in zip(handles, results):
-                if h.trace is not None and r.flags:
-                    for f in r.flags:
-                        h.trace.event("guardrail_flag", reason=f.reason,
-                                      severity=f.severity,
-                                      replica=self.replica_id,
-                                      tier=self.tier)
-                if r.flags:
-                    # triage, hook first (no replica locks held): the
-                    # pool may take ownership and re-run one tier up
-                    if self._on_flagged is not None \
-                            and self._on_flagged(self, h, r):
-                        continue
-                    fatal = next((f for f in r.flags if f.fatal), None)
-                    if fatal is not None:
-                        h._resolve(error=GuardrailViolation(
-                            f"guardrail {fatal.reason}: result withheld "
-                            f"(replica {self.replica_id}, tier {self.tier})",
-                            reason=fatal.reason, severity=fatal.severity,
-                            detail={"value": fatal.value,
-                                    "limit": fatal.limit,
-                                    "mode": self.tier,
-                                    "replica_id": self.replica_id}),
-                            replica_id=self.replica_id)
-                        continue
-                    # suspect-only with nowhere to go: deliver annotated
-                h._resolve(result=r, replica_id=self.replica_id)
+            with clock.resolve():
+                expropriated = self._resolve_flush(
+                    clock, engine, cap, handles, reason, depth, wait_s,
+                    service_s, t0, results)
             if expropriated:
                 return
+
+    def _resolve_flush(self, clock: FlushClock, engine: QuantizedEngine,
+                       cap: int, handles: List[RequestHandle], reason: str,
+                       depth: int, wait_s: float, service_s: float,
+                       t0: float, results: list) -> bool:
+        """Bookkeeping of one served flush, then each handle's triage and
+        resolution. Returns whether the pool expropriated the replica
+        meanwhile (the worker then exits)."""
+        # stamp the escalation audit trail the pool appended to each
+        # handle (and the obs trace id) into its delivered result
+        results = [dataclasses.replace(
+                       r, replica_id=self.replica_id,
+                       escalations=tuple(h.escalations),
+                       trace_id=(h.trace.trace_id
+                                 if h.trace is not None else ""))
+                   for h, r in zip(handles, results)]
+        trace_ids = tuple(h.trace.trace_id for h in handles
+                          if h.trace is not None)
+        rec = clock.record(
+            engine, capacity=cap, n_requests=len(handles), reason=reason,
+            queue_depth=depth, wait_s=wait_s, service_s=service_s,
+            path=results[0].path, batch_size=results[0].batch_size,
+            replica_id=self.replica_id, trace_ids=trace_ids, t_start=t0)
+        with self._lock:
+            self._busy_since = None
+            self._in_flight = []
+            expropriated = self._expropriated
+            self._n_completed += len(handles)
+            self._consecutive_errors = 0
+            self._last_beat = time.monotonic()
+            self._flushes.append(rec)
+            # feed the circuit-breaker window (flush results only —
+            # chunk health is the session layer's concern)
+            for r in results:
+                self._recent_flags.append(bool(r.flags))
+            self._n_flagged += sum(1 for r in results if r.flags)
+        self._m_completed.inc(len(handles))
+        self._m_wait.observe(wait_s)
+        self._m_service.observe(service_s)
+        self._m_service_r.observe(service_s)
+        for h, r in zip(handles, results):
+            if h.trace is not None and r.flags:
+                for f in r.flags:
+                    h.trace.event("guardrail_flag", reason=f.reason,
+                                  severity=f.severity,
+                                  replica=self.replica_id,
+                                  tier=self.tier)
+            if r.flags:
+                # triage, hook first (no replica locks held): the
+                # pool may take ownership and re-run one tier up
+                if self._on_flagged is not None \
+                        and self._on_flagged(self, h, r):
+                    continue
+                fatal = next((f for f in r.flags if f.fatal), None)
+                if fatal is not None:
+                    h._resolve(error=GuardrailViolation(
+                        f"guardrail {fatal.reason}: result withheld "
+                        f"(replica {self.replica_id}, tier {self.tier})",
+                        reason=fatal.reason, severity=fatal.severity,
+                        detail={"value": fatal.value,
+                                "limit": fatal.limit,
+                                "mode": self.tier,
+                                "replica_id": self.replica_id}),
+                        replica_id=self.replica_id)
+                    continue
+                # suspect-only with nowhere to go: deliver annotated
+            h._resolve(result=r, replica_id=self.replica_id)
+        return expropriated

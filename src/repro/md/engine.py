@@ -46,6 +46,7 @@ from repro.kernels import ops
 from repro.md.neighbor import NeighborList, build_neighbor_list, maybe_rebuild
 from repro.md.nve import _FS
 from repro.obs.metrics import REGISTRY
+from repro.obs.trace import stage
 from repro.models import so3krates as so3
 from repro.serving.bucketing import EDGE_LANE, count_edges
 from repro.serving.forward import sparse_energy_and_forces
@@ -341,56 +342,69 @@ class MDEngine:
         lengths = [record_every] * n_records + ([tail] if tail else [])
         recs = []
         e_ref: Optional[np.ndarray] = None   # first checkpoint's e_tot
-        for length in lengths:
-            state, rec = self._segment_jit(state, species, mask, masses,
-                                           length=length)
-            if bool(state.nlist.overflow):   # the per-checkpoint host sync
-                raise RuntimeError(
-                    "skin neighbour list overflowed its edge capacity "
-                    f"({state.nlist.edge_capacity}) during the run; raise "
-                    "MDConfig.edge_capacity / edge_capacity_safety")
-            # guardrails ride the same host sync: non-finite energies and
-            # (when armed) per-replica e_tot drift vs the first checkpoint
-            if self.md.check_finite or self.md.drift_limit is not None:
-                e_tot = np.asarray(rec["e_tot"])
-                if self.md.check_finite:
-                    bad = check_finite_tree(
-                        {"e_tot": e_tot, "e_pot": np.asarray(rec["e_pot"])})
-                    if bad is not None:
-                        raise GuardrailViolation(
-                            f"non-finite {bad} at an MD checkpoint (mode "
-                            f"{self.md.mode}) — the trajectory exploded",
-                            reason="nonfinite", severity="fatal",
-                            detail={"mode": self.md.mode, "array": bad})
-                if self.md.drift_limit is not None:
-                    if e_ref is None:
-                        e_ref = e_tot
-                    else:
-                        drift = float(np.abs(e_tot - e_ref).max())
-                        # SLO feed: drift as a fraction of the limit
-                        # (> 1.0 breaches md_energy_drift) — published
-                        # whether or not the guardrail trips, so the
-                        # health plane sees drift *approaching* the
-                        # limit too
-                        REGISTRY.gauge(
-                            "md_energy_drift_ratio",
-                            mode=self.md.mode).set(
-                            drift / self.md.drift_limit)
-                        if drift > self.md.drift_limit:
-                            raise GuardrailViolation(
-                                f"energy drift {drift:.4g} eV exceeds "
-                                f"drift_limit={self.md.drift_limit} eV "
-                                f"(mode {self.md.mode})",
-                                reason="energy_drift", severity="suspect",
-                                detail={"mode": self.md.mode,
-                                        "value": drift,
-                                        "limit": self.md.drift_limit})
+        for k, length in enumerate(lengths):
+            # host spans on the profiler's clock: the segment's dispatch,
+            # then the checkpoint's host reads (the per-segment sync)
+            with stage("md.segment", segment=k):
+                state, rec = self._segment_jit(state, species, mask, masses,
+                                               length=length)
+            with stage("md.sync", segment=k):
+                e_ref = self._checkpoint(state, rec, e_ref)
             recs.append(rec)
-        records = {k: np.stack([np.asarray(r[k]) for r in recs])
-                   for k in recs[0]} if recs else {}
-        records["n_rebuilds"] = int(state.nlist.n_rebuilds)
-        records["missed_edges"] = int(state.missed)
+        with stage("md.sync", segment=len(lengths)):
+            records = {k: np.stack([np.asarray(r[k]) for r in recs])
+                       for k in recs[0]} if recs else {}
+            records["n_rebuilds"] = int(state.nlist.n_rebuilds)
+            records["missed_edges"] = int(state.missed)
         return state, records
+
+    def _checkpoint(self, state: ReplicaState, rec: Dict,
+                    e_ref: Optional[np.ndarray]) -> Optional[np.ndarray]:
+        """The host checks at one record checkpoint: the overflow flag,
+        then the guardrails. Returns the drift reference (the first
+        checkpoint's e_tot)."""
+        if bool(state.nlist.overflow):   # the per-checkpoint host sync
+            raise RuntimeError(
+                "skin neighbour list overflowed its edge capacity "
+                f"({state.nlist.edge_capacity}) during the run; raise "
+                "MDConfig.edge_capacity / edge_capacity_safety")
+        # guardrails ride the same host sync: non-finite energies and
+        # (when armed) per-replica e_tot drift vs the first checkpoint
+        if self.md.check_finite or self.md.drift_limit is not None:
+            e_tot = np.asarray(rec["e_tot"])
+            if self.md.check_finite:
+                bad = check_finite_tree(
+                    {"e_tot": e_tot, "e_pot": np.asarray(rec["e_pot"])})
+                if bad is not None:
+                    raise GuardrailViolation(
+                        f"non-finite {bad} at an MD checkpoint (mode "
+                        f"{self.md.mode}) — the trajectory exploded",
+                        reason="nonfinite", severity="fatal",
+                        detail={"mode": self.md.mode, "array": bad})
+            if self.md.drift_limit is not None:
+                if e_ref is None:
+                    e_ref = e_tot
+                else:
+                    drift = float(np.abs(e_tot - e_ref).max())
+                    # SLO feed: drift as a fraction of the limit
+                    # (> 1.0 breaches md_energy_drift) — published
+                    # whether or not the guardrail trips, so the
+                    # health plane sees drift *approaching* the
+                    # limit too
+                    REGISTRY.gauge(
+                        "md_energy_drift_ratio",
+                        mode=self.md.mode).set(
+                        drift / self.md.drift_limit)
+                    if drift > self.md.drift_limit:
+                        raise GuardrailViolation(
+                            f"energy drift {drift:.4g} eV exceeds "
+                            f"drift_limit={self.md.drift_limit} eV "
+                            f"(mode {self.md.mode})",
+                            reason="energy_drift", severity="suspect",
+                            detail={"mode": self.md.mode,
+                                    "value": drift,
+                                    "limit": self.md.drift_limit})
+        return e_ref
 
     # -- introspection -------------------------------------------------------
 

@@ -2,13 +2,15 @@
 plus the active health plane (SLOs, burn-rate alerting, anomaly
 detection, Chrome-trace timeline export).
 
-Dependency leaf (stdlib only, like ``repro.guardrails``): everything in
+Dependency leaf (stdlib only, like ``repro.guardrails``; JAX is imported
+lazily, by the stage spans and runtime counters alone): everything in
 the stack can import it. See docs/observability.md.
 """
 from repro.obs.metrics import (MetricsRegistry, Counter, Gauge, Histogram,
                                REGISTRY, get_registry, snapshot)
 from repro.obs.trace import (Span, RequestTrace, Tracer, TRACER,
-                             configure_tracing, get_tracer)
+                             configure_tracing, get_tracer, stage,
+                             RuntimeCounters, RUNTIME)
 from repro.obs.export import (prometheus_text, write_metrics,
                               JsonlTraceSink, PeriodicExporter,
                               load_traces)
@@ -25,7 +27,7 @@ __all__ = [
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "REGISTRY",
     "get_registry", "snapshot",
     "Span", "RequestTrace", "Tracer", "TRACER", "configure_tracing",
-    "get_tracer",
+    "get_tracer", "stage", "RuntimeCounters", "RUNTIME",
     "prometheus_text", "write_metrics", "JsonlTraceSink",
     "PeriodicExporter", "load_traces",
     "Alert", "AlertBus", "SLO", "SLOEvaluator", "HealthMonitor",

@@ -28,17 +28,28 @@ overhead gate in ``BENCH_obs.json`` pins this at <= 1.05x.
 All span timestamps are ``time.monotonic()`` (duration math); the only
 wall-clock field is ``wall_time``, stamped once at ``finish`` for
 export/correlation (see the time-base policy in docs/observability.md).
+
+The serving worker's own hot path is timed by :class:`stage` (one span
+per stage of a flush, both on the profiler's host plane and summed into
+the flush's breakdown dict) and by two process-wide runtime counters,
+XLA compiles and Python GC pauses (:data:`RUNTIME`).
+JAX is imported lazily, on the first stage or at install, so the rest of
+the module stays stdlib-only.
 """
 from __future__ import annotations
 
+import gc
 import itertools
 import threading
 import time
 from collections import deque
 from typing import Callable, Dict, List, Optional
 
+from repro.obs.metrics import REGISTRY, Counter
+
 __all__ = ["Span", "RequestTrace", "Tracer", "TRACER",
-           "configure_tracing", "get_tracer"]
+           "configure_tracing", "get_tracer", "stage", "RuntimeCounters",
+           "RUNTIME"]
 
 
 class Span:
@@ -322,3 +333,147 @@ def configure_tracing(enabled: Optional[bool] = None, sink=None,
 
 def get_tracer() -> Tracer:
     return TRACER
+
+
+# -- stage spans on the profiler's clock -------------------------------------
+
+_annotation_cls = None
+
+
+def _annotation(name: str, **args):
+    """A ``jax.profiler.TraceAnnotation``: an event on the profiler's host
+    plane while a profile records, about a microsecond otherwise."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls(name, **args)
+
+
+class stage:
+    """One stage of a worker's hot path, timed once for two readers.
+
+    ``with stage("engine.prep", acc):`` opens a profiler annotation named
+    ``name`` (carrying ``args`` as its arguments), so a recorded profile
+    shows the stage on the device trace's clock; and adds the stage's
+    ``time.monotonic()`` duration to ``acc[key]`` (``key`` defaults to the
+    name's last dotted part plus ``_s``: ``engine.prep`` -> ``prep_s``),
+    the breakdown a ``FlushRecord`` is built from. ``acc=None`` only
+    annotates. ``t0``/``t1`` hold the stage's monotonic start and end.
+    """
+
+    __slots__ = ("name", "acc", "key", "args", "t0", "t1", "_ann")
+
+    def __init__(self, name: str, acc: Optional[Dict[str, float]] = None,
+                 key: Optional[str] = None, **args):
+        self.name = name
+        self.acc = acc
+        self.key = key if key is not None else name.rsplit(".", 1)[-1] + "_s"
+        self.args = args
+        self.t0 = self.t1 = 0.0
+
+    def __enter__(self) -> "stage":
+        self._ann = _annotation(self.name, **self.args)
+        self._ann.__enter__()
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = time.monotonic()
+        self._ann.__exit__(*exc)
+        if self.acc is not None:
+            self.acc[self.key] = (self.acc.get(self.key, 0.0)
+                                  + self.t1 - self.t0)
+        return False
+
+
+# -- process-wide runtime counters: XLA compiles and Python GC pauses --------
+
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+class RuntimeCounters:
+    """XLA compiles and Python GC pauses of the whole process.
+
+    :meth:`install` hooks both, once per process (the hooks are
+    process-wide, so is :data:`RUNTIME`, the one instance):
+
+    - compiles: a ``jax.monitoring`` duration listener on the
+      backend-compile event, which fires for a compile and for a load
+      from the persistent compilation cache alike; a cache-hit event
+      raised earlier on the same thread marks the load. Each feeds
+      ``jax_compiles_total{source=compile|cache}`` and :attr:`compiles`.
+    - GC: a ``gc.callbacks`` hook times every collection into
+      ``python_gc_seconds_total{generation}`` and :attr:`gc_s`, and holds
+      a ``python.gc`` profiler annotation open from its start to its
+      stop, so a pause shows on the host plane.
+
+    ``compiles`` and ``gc_s`` are running totals since install; a reader
+    takes differences (``FlushRecord.compiles``/``gc_s``).
+    """
+
+    def __init__(self):
+        self.compiles = 0        # compiles + persistent-cache loads
+        self.gc_s = 0.0          # GC pause seconds
+        self._installed = False
+        self._lock = threading.Lock()
+        self._gc_counters: Dict[int, Counter] = {}
+        self._local = threading.local()
+
+    def install(self) -> None:
+        """Idempotent. Each call re-binds the GC counters to the registry:
+        a collection can interrupt the registry's own lock, so the GC hook
+        only bumps counters it already holds."""
+        global _annotation_cls
+        for g in range(3):
+            self._gc_counters[g] = REGISTRY.counter(
+                "python_gc_seconds_total", generation=str(g))
+        with self._lock:
+            if self._installed:
+                return
+            self._installed = True
+        from jax import monitoring
+        from jax.profiler import TraceAnnotation
+        _annotation_cls = TraceAnnotation     # no import inside a collection
+        monitoring.register_event_listener(self._on_jax_event)
+        monitoring.register_event_duration_secs_listener(
+            self._on_jax_duration)
+        gc.callbacks.append(self._on_gc)
+
+    def _on_jax_event(self, event: str, **kwargs) -> None:
+        if event == CACHE_HIT_EVENT:
+            self._local.cache_hit = True
+
+    def _on_jax_duration(self, event: str, duration: float,
+                         **kwargs) -> None:
+        if event != BACKEND_COMPILE_EVENT:
+            return
+        hit = getattr(self._local, "cache_hit", False)
+        self._local.cache_hit = False
+        with self._lock:
+            self.compiles += 1
+        REGISTRY.counter("jax_compiles_total",
+                         source="cache" if hit else "compile").inc()
+
+    def _on_gc(self, phase: str, info: Dict) -> None:
+        # runs inside the collection, on whichever thread triggered it;
+        # CPython runs one collection at a time, so gc_s has one writer
+        if phase == "start":
+            ann = _annotation("python.gc", generation=info["generation"])
+            ann.__enter__()
+            self._local.gc = (time.monotonic(), ann)
+            return
+        started = getattr(self._local, "gc", None)
+        if started is None:       # hooked in the middle of a collection
+            return
+        self._local.gc = None
+        t0, ann = started
+        dt = time.monotonic() - t0
+        ann.__exit__(None, None, None)
+        self.gc_s += dt
+        self._gc_counters[info["generation"]].inc(dt)
+
+
+#: The process's runtime counters; engines install them at construction.
+RUNTIME = RuntimeCounters()

@@ -63,7 +63,7 @@ from repro.obs.metrics import REGISTRY
 from repro.obs.trace import TRACER
 from repro.serving.bucketing import BucketSpec, Graph, assign_bucket
 from repro.serving.engine import QuantizedEngine, MoleculeResult
-from repro.server.stats import FlushRecord, flush_summary
+from repro.server.stats import FlushClock, FlushRecord, flush_summary
 
 __all__ = ["SchedulerConfig", "SchedulerClosed", "SchedulerOverloaded",
            "RequestTimeout", "RequestHandle", "BatchQueue",
@@ -449,8 +449,9 @@ class MicroBatchScheduler:
     # -- worker side --------------------------------------------------------
 
     def _serve_loop(self):
+        clock = FlushClock()
         while True:
-            with self._lock:
+            with clock.wait(), self._lock:
                 while True:
                     now = time.monotonic()
                     depth = self._queue.depth()
@@ -482,50 +483,52 @@ class MicroBatchScheduler:
                     h._resolve(error=e, replica_id=0)
                 continue
             service_s = time.monotonic() - t0
-            # bookkeeping strictly before resolving: a client returning
-            # from result() must already see this flush in stats()
-            n_flagged = sum(1 for r in results if r.flags)
-            trace_ids = tuple(h.trace.trace_id for h in handles
-                              if h.trace is not None)
-            # stub engines in tests may not expose the profiling hook
-            bd = getattr(self.engine, "last_infer_breakdown", None) or {}
-            with self._lock:
-                self._n_completed += len(handles)
-                self._n_guard_flagged += n_flagged
-                self._service_ema = (service_s if self._service_ema is None
-                                     else 0.8 * self._service_ema
-                                     + 0.2 * service_s)
-                self._flushes.append(FlushRecord(
-                    capacity=cap, n_requests=len(handles), reason=reason,
-                    queue_depth=depth, wait_s=wait_s, service_s=service_s,
-                    path=results[0].path, batch_size=results[0].batch_size,
-                    replica_id=0, trace_ids=trace_ids,
-                    prep_s=bd.get("prep_s", 0.0),
-                    dispatch_s=bd.get("dispatch_s", 0.0),
-                    sync_s=bd.get("sync_s", 0.0),
-                    t_start=t0))
-            self._m_requests["completed"].inc(len(handles))
-            if n_flagged:
-                self._m_requests["guard_flagged"].inc(n_flagged)
-            self._m_wait.observe(wait_s)
-            self._m_service.observe(service_s)
-            REGISTRY.counter("serve_flushes_total", surface="scheduler",
-                             reason=reason).inc()
-            for h, r in zip(handles, results):
-                if h.trace is not None:
-                    r = dataclasses.replace(r, trace_id=h.trace.trace_id)
-                    for f in r.flags:
-                        h.trace.event("guardrail_flag", reason=f.reason,
-                                      severity=f.severity)
-                # fatal flags (non-finite values) are never delivered:
-                # the single-engine scheduler has no higher tier to
-                # escalate to, so the handle gets the typed error.
-                # Suspect flags ride out annotated in result.flags.
-                fatal = next((f for f in r.flags if f.fatal), None)
-                if fatal is not None:
-                    h._resolve(error=GuardrailViolation(
-                        f"guardrail {fatal.reason}: result withheld",
-                        reason=fatal.reason, severity=fatal.severity),
-                        replica_id=0)
-                else:
-                    h._resolve(result=r, replica_id=0)
+            with clock.resolve():
+                self._resolve_flush(clock, cap, handles, reason, depth,
+                                    wait_s, service_s, t0, results)
+
+    def _resolve_flush(self, clock: FlushClock, cap: int,
+                       handles: List[RequestHandle], reason: str,
+                       depth: int, wait_s: float, service_s: float,
+                       t0: float, results: List[MoleculeResult]) -> None:
+        # bookkeeping strictly before resolving: a client returning
+        # from result() must already see this flush in stats()
+        n_flagged = sum(1 for r in results if r.flags)
+        trace_ids = tuple(h.trace.trace_id for h in handles
+                          if h.trace is not None)
+        rec = clock.record(
+            self.engine, capacity=cap, n_requests=len(handles),
+            reason=reason, queue_depth=depth, wait_s=wait_s,
+            service_s=service_s, path=results[0].path,
+            batch_size=results[0].batch_size, replica_id=0,
+            trace_ids=trace_ids, t_start=t0)
+        with self._lock:
+            self._n_completed += len(handles)
+            self._n_guard_flagged += n_flagged
+            self._service_ema = (service_s if self._service_ema is None
+                                 else 0.8 * self._service_ema
+                                 + 0.2 * service_s)
+            self._flushes.append(rec)
+        self._m_requests["completed"].inc(len(handles))
+        if n_flagged:
+            self._m_requests["guard_flagged"].inc(n_flagged)
+        self._m_wait.observe(wait_s)
+        self._m_service.observe(service_s)
+        for h, r in zip(handles, results):
+            if h.trace is not None:
+                r = dataclasses.replace(r, trace_id=h.trace.trace_id)
+                for f in r.flags:
+                    h.trace.event("guardrail_flag", reason=f.reason,
+                                  severity=f.severity)
+            # fatal flags (non-finite values) are never delivered:
+            # the single-engine scheduler has no higher tier to
+            # escalate to, so the handle gets the typed error.
+            # Suspect flags ride out annotated in result.flags.
+            fatal = next((f for f in r.flags if f.fatal), None)
+            if fatal is not None:
+                h._resolve(error=GuardrailViolation(
+                    f"guardrail {fatal.reason}: result withheld",
+                    reason=fatal.reason, severity=fatal.severity),
+                    replica_id=0)
+            else:
+                h._resolve(result=r, replica_id=0)

@@ -15,7 +15,9 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
-__all__ = ["latency_summary", "FlushRecord", "flush_summary"]
+from repro.obs.trace import RUNTIME, stage
+
+__all__ = ["latency_summary", "FlushRecord", "FlushClock", "flush_summary"]
 
 
 def latency_summary(latencies_s: Sequence[float],
@@ -55,14 +57,82 @@ class FlushRecord:
     # obs linkage: trace ids of the requests in this flush (empty when
     # tracing is disabled); joins flush telemetry to per-request traces
     trace_ids: tuple = ()
-    # per-flush serve-time breakdown from the engine profiling hooks
-    # (repro.obs): prep (padding), dispatch (kernel submit), device sync
+    # per-flush serve-time breakdown from the engine's stage spans
+    # (repro.obs.trace.stage): prep (padding), dispatch (kernel submit),
+    # device sync, unpack (rows into MoleculeResults), guard (guardrail
+    # checks and the LEE probe)
     prep_s: float = 0.0
     dispatch_s: float = 0.0
     sync_s: float = 0.0
     # monotonic flush start time: places the flush on the fleet
     # timeline (repro.obs.timeline); 0.0 = recorded pre-timeline
     t_start: float = 0.0
+    unpack_s: float = 0.0
+    guard_s: float = 0.0
+    # worker time from the previous flush's sync end to this flush's
+    # dispatch end, when the device holds no work of this worker's
+    # (0.0 on a worker's first flush), and the part of it spent waiting
+    # for a flush to trigger (sched.wait)
+    gap_s: float = 0.0
+    idle_s: float = 0.0
+    # process-wide runtime counters since the worker's previous flush:
+    # XLA compiles plus compile-cache loads, and Python GC pause seconds
+    compiles: int = 0
+    gc_s: float = 0.0
+
+
+class FlushClock:
+    """Stage spans and ``FlushRecord`` of one serving worker's flushes.
+
+    The single-engine scheduler and every cluster replica run one each,
+    so their spans and records cannot drift apart. Per flush the worker
+    thread shows, flat and in order on the profiler's host plane:
+    ``sched.wait`` (:meth:`wait`), the engine's ``engine.prep`` /
+    ``engine.dispatch`` / ``engine.sync`` / ``engine.unpack`` /
+    ``engine.guard``, then ``sched.resolve`` (:meth:`resolve`). No span
+    encloses a whole flush: the flush's index rides each worker span as
+    its ``flush`` argument instead. :meth:`record` builds the
+    ``FlushRecord`` from the engine's breakdown and the runtime counters.
+    """
+
+    def __init__(self):
+        RUNTIME.install()
+        self.n = 0                      # flushes recorded
+        self._idle: Dict[str, float] = {}
+        self._synced: Optional[float] = None
+        self._compiles = RUNTIME.compiles
+        self._gc_s = RUNTIME.gc_s
+
+    def wait(self) -> stage:
+        """Span of the worker looking for work until a flush triggers."""
+        return stage("sched.wait", self._idle, "idle_s", flush=self.n)
+
+    def resolve(self) -> stage:
+        """Span of the flush's bookkeeping and its handles' resolution."""
+        return stage("sched.resolve", flush=self.n)
+
+    def record(self, engine, **fields) -> FlushRecord:
+        """The flush's record: ``fields`` plus the breakdown ``engine``
+        left for its last ``infer_batch`` (stub engines leave none)."""
+        bd = getattr(engine, "last_infer_breakdown", None) or {}
+        compiles, gc_s = RUNTIME.compiles, RUNTIME.gc_s
+        first = self._synced is None or "t_dispatched" not in bd
+        rec = FlushRecord(
+            **fields,
+            prep_s=bd.get("prep_s", 0.0),
+            dispatch_s=bd.get("dispatch_s", 0.0),
+            sync_s=bd.get("sync_s", 0.0),
+            unpack_s=bd.get("unpack_s", 0.0),
+            guard_s=bd.get("guard_s", 0.0),
+            gap_s=0.0 if first else bd["t_dispatched"] - self._synced,
+            idle_s=0.0 if first else self._idle.get("idle_s", 0.0),
+            compiles=compiles - self._compiles,
+            gc_s=gc_s - self._gc_s)
+        self._synced = bd.get("t_synced")
+        self._compiles, self._gc_s = compiles, gc_s
+        self._idle.clear()
+        self.n += 1
+        return rec
 
 
 def flush_summary(flushes: Sequence[FlushRecord]) -> Dict[str, object]:

@@ -45,6 +45,7 @@ from repro.guardrails import (Flag, GuardrailConfig, GuardrailViolation,
                               check_result)
 from repro.models import so3krates as so3
 from repro.obs.metrics import REGISTRY
+from repro.obs.trace import RUNTIME, stage
 from repro.serving.bucketing import (BucketSpec, Graph, build_edge_list,
                                      count_edges, pad_graphs, plan_batches)
 from repro.serving.forward import (batched_energy_and_forces,
@@ -232,9 +233,10 @@ class QuantizedEngine:
                                 mode=serve.mode, event=k)
             for k in self.guard_stats}
         # per-(bucket, batch_size, path) warmup/compile accounting and
-        # the last _infer_raw stage breakdown (obs profiling hooks)
+        # the last infer_batch's stage breakdown (obs profiling hooks)
         self.warmup_report: List[Dict] = []
         self.last_infer_breakdown: Dict[str, float] = {}
+        RUNTIME.install()
 
     # -- construction -------------------------------------------------------
 
@@ -366,10 +368,7 @@ class QuantizedEngine:
                     _timed("sparse", cap, bsz,
                            lambda: self._run_sparse(species, coords,
                                                     mask, el))
-        total = time.monotonic() - t0
-        REGISTRY.counter("engine_warmup_seconds_total",
-                         mode=self.serve.mode).inc(total)
-        return total
+        return time.monotonic() - t0
 
     def _run_dense(self, species, coords, mask):
         self.compiled_shapes.add(species.shape)
@@ -440,8 +439,27 @@ class QuantizedEngine:
         results come back with ``MoleculeResult.flags`` set and the
         caller triages: typed error, annotated delivery, or a precision
         escalation).
+
+        Each stage runs in a :class:`repro.obs.trace.stage` span
+        (``engine.prep``/``dispatch``/``sync``/``unpack``/``guard``);
+        ``last_infer_breakdown`` keeps their seconds, plus the monotonic
+        end of the first dispatch (``t_dispatched``) and of the last sync
+        (``t_synced``), for the serving worker's ``FlushRecord``.
         """
-        results = self._infer_raw(graphs)
+        bd: Dict[str, float] = {}
+        try:
+            results = self._infer_raw(graphs, bd)
+            with stage("engine.guard", bd):
+                return self._guard(graphs, results, on_flag)
+        finally:
+            # read by the scheduler/replica worker right after
+            # infer_batch returns, on the same thread
+            self.last_infer_breakdown = bd
+
+    def _guard(self, graphs: Sequence[Graph],
+               results: List[MoleculeResult],
+               on_flag: Optional[str]) -> List[MoleculeResult]:
+        """The guardrail pass of ``infer_batch``."""
         g = self.guardrails
         if not g.active:
             return results
@@ -480,40 +498,38 @@ class QuantizedEngine:
         return [dataclasses.replace(r, flags=flagged[i]) if i in flagged
                 else r for i, r in enumerate(results)]
 
-    def _infer_raw(self, graphs: Sequence[Graph]) -> List[MoleculeResult]:
+    def _infer_raw(self, graphs: Sequence[Graph],
+                   bd: Optional[Dict[str, float]] = None
+                   ) -> List[MoleculeResult]:
         """The bucket/pad/dispatch pipeline with no guardrail pass —
         also the re-run path of the LEE probe and ``lee_diagnostic``
-        (probing the probe would recurse)."""
-        t_start = time.monotonic()
+        (probing the probe would recurse). Stage seconds accumulate into
+        ``bd`` when given (see ``infer_batch``)."""
         plans = plan_batches(graphs, self._buckets)
-        prep_s = dispatch_s = sync_s = 0.0
         results: List[Optional[MoleculeResult]] = [None] * len(graphs)
         for plan in plans:
-            t0 = time.monotonic()
-            species, coords, mask = pad_graphs(
-                graphs, plan, pad_species=self.serve.pad_species)
-            t1 = time.monotonic()
-            e, f, path = self._dispatch(species, coords, mask, plan.bucket)
-            t2 = time.monotonic()
+            with stage("engine.prep", bd):
+                species, coords, mask = pad_graphs(
+                    graphs, plan, pad_species=self.serve.pad_species)
+            with stage("engine.dispatch", bd) as st:
+                e, f, path = self._dispatch(species, coords, mask,
+                                            plan.bucket)
+            if bd is not None:
+                bd.setdefault("t_dispatched", st.t1)
             # np.asarray forces device->host transfer: the sync point
-            e = np.asarray(e)
-            f = np.asarray(f)
-            t3 = time.monotonic()
-            prep_s += t1 - t0
-            dispatch_s += t2 - t1
-            sync_s += t3 - t2
-            for row, gi in enumerate(plan.graph_indices):
-                n = graphs[gi].n_atoms
-                results[gi] = MoleculeResult(
-                    energy=float(e[row]), forces=f[row, :n],
-                    n_atoms=n, bucket_capacity=plan.bucket.capacity,
-                    batch_size=plan.batch_size, path=path,
-                    artifact_version=self.artifact_version)
-        # per-flush serve-time breakdown (read by the scheduler/replica
-        # worker right after infer_batch returns, same thread)
-        self.last_infer_breakdown = {
-            "prep_s": prep_s, "dispatch_s": dispatch_s, "sync_s": sync_s,
-            "n_plans": len(plans), "total_s": time.monotonic() - t_start}
+            with stage("engine.sync", bd) as st:
+                e = np.asarray(e)
+                f = np.asarray(f)
+            if bd is not None:
+                bd["t_synced"] = st.t1
+            with stage("engine.unpack", bd):
+                for row, gi in enumerate(plan.graph_indices):
+                    n = graphs[gi].n_atoms
+                    results[gi] = MoleculeResult(
+                        energy=float(e[row]), forces=f[row, :n],
+                        n_atoms=n, bucket_capacity=plan.bucket.capacity,
+                        batch_size=plan.batch_size, path=path,
+                        artifact_version=self.artifact_version)
         return results  # type: ignore[return-value]
 
     def _lee_probe(self, graphs: Sequence[Graph],

@@ -24,6 +24,14 @@ padded atoms never enter any edge or pair, contribute exactly zero
 energy, and receive exactly zero force. ``tests/test_serving.py`` and
 ``tests/test_sparse_serving.py`` pin sparse == dense <= 1e-5 on energies
 and forces.
+
+Both paths name their stages with ``jax.named_scope`` — ``geometry``;
+per layer ``layer{i}`` holding ``trunk``, ``radial``, ``attention``,
+``messages``, ``update``, ``mddq_snap`` and ``vnorm_feedback``; then
+``readout`` — so a profile attributes device time to a stage by name
+whatever the compiler calls its fusions. The force pass inherits the
+names through JAX's name stack. Scopes are op metadata only: the
+compiled program is the same with or without them.
 """
 from __future__ import annotations
 
@@ -86,9 +94,10 @@ def _quant_vectors(v: jnp.ndarray, cfg: So3kratesConfig,
     v == 0 forever; both implementations map zero vectors to exactly zero
     and are NaN-safe there (core/mddq._split).
     """
-    if mddq_kernel:
-        return ops.mddq_qdq_kernel(v, cfg.mddq(), codebook)
-    return mddq_fake_quant(v, cfg.mddq(), codebook)
+    with jax.named_scope("mddq_snap"):
+        if mddq_kernel:
+            return ops.mddq_qdq_kernel(v, cfg.mddq(), codebook)
+        return mddq_fake_quant(v, cfg.mddq(), codebook)
 
 
 def batched_energy(qparams: QuantizedParams, cfg: So3kratesConfig,
@@ -109,48 +118,72 @@ def batched_energy(qparams: QuantizedParams, cfg: So3kratesConfig,
     if codebook is None and quant_vectors:
         codebook = make_codebook(cfg.dir_bits)
 
-    _, u, rbf, pair_mask = pair_geometry(coords, cfg, mask)  # (B, n, n, .)
+    with jax.named_scope("geometry"):
+        _, u, rbf, pair_mask = pair_geometry(coords, cfg, mask)  # (B,n,n,.)
 
     x = qparams["embed"][species] * mask[..., None]          # (B, n, F)
     v = jnp.zeros((B, n, cfg.vec_feat, 3))
 
+    # scopes follow the computation's order, so a stage whose work is
+    # interleaved with another's (trunk, radial) is entered more than once
     for i in range(cfg.n_layers):
         L = f"layer{i}"
-        xn = _layernorm(x, qparams[f"{L}/ln_g"], qparams[f"{L}/ln_b"])
+        with jax.named_scope(L):
+            with jax.named_scope("trunk"):
+                xn = _layernorm(x, qparams[f"{L}/ln_g"],
+                                qparams[f"{L}/ln_b"])
+                q = _dense(xn, qparams[f"{L}/wq"], use_kernels)
+                k = _dense(xn, qparams[f"{L}/wk"], use_kernels)
+            with jax.named_scope("radial"):
+                bias = (rbf @ qparams[f"{L}/rbf_bias"])[..., 0]  # (B,n,n)
+            with jax.named_scope("attention"):
+                logits = cosine_logits(q, k, bias, cfg, cfg.robust_attention)
+                logits = jnp.where(pair_mask, logits, -1e9)
+                alpha = jax.nn.softmax(logits, axis=-1)      # (B, n, n)
 
-        q = _dense(xn, qparams[f"{L}/wq"], use_kernels)
-        k = _dense(xn, qparams[f"{L}/wk"], use_kernels)
-        bias = (rbf @ qparams[f"{L}/rbf_bias"])[..., 0]      # (B, n, n)
-        logits = cosine_logits(q, k, bias, cfg, cfg.robust_attention)
-        logits = jnp.where(pair_mask, logits, -1e9)
-        alpha = jax.nn.softmax(logits, axis=-1)              # (B, n, n)
+            # invariant messages (gate is rbf-masked -> padded pairs drop
+            # out)
+            with jax.named_scope("trunk"):
+                msg = _dense(xn, qparams[f"{L}/wm"], use_kernels)
+            with jax.named_scope("radial"):
+                gate = rbf @ qparams[f"{L}/rbf_m"]           # (B, n, n, F)
+            with jax.named_scope("messages"):
+                x = x + jnp.einsum("bij,bijf->bif", alpha,
+                                   gate * msg[:, None, :, :])
+            with jax.named_scope("update"):
+                h = jax.nn.silu(_dense(x, qparams[f"{L}/w_upd1"],
+                                       use_kernels))
+                x = x + _dense(h, qparams[f"{L}/w_upd2"], use_kernels)
 
-        # invariant messages (gate is rbf-masked -> padded pairs drop out)
-        msg = _dense(xn, qparams[f"{L}/wm"], use_kernels)
-        gate = rbf @ qparams[f"{L}/rbf_m"]                   # (B, n, n, F)
-        x = x + jnp.einsum("bij,bijf->bif", alpha,
-                           gate * msg[:, None, :, :])
-        h = jax.nn.silu(_dense(x, qparams[f"{L}/w_upd1"], use_kernels))
-        x = x + _dense(h, qparams[f"{L}/w_upd2"], use_kernels)
+            # equivariant messages: invariant coefficients x geometric
+            # directions
+            with jax.named_scope("trunk"):
+                pa = _dense(xn, qparams[f"{L}/wa"], use_kernels)[:, None]
+            with jax.named_scope("radial"):
+                ra = rbf @ qparams[f"{L}/rbf_a"]
+            with jax.named_scope("messages"):
+                ca = pa * ra                                 # (B,n,n,Fv)
+            with jax.named_scope("trunk"):
+                pb = _dense(xn, qparams[f"{L}/wb"], use_kernels)[:, None]
+            with jax.named_scope("radial"):
+                rb = rbf @ qparams[f"{L}/rbf_b"]
+            with jax.named_scope("messages"):
+                cb = pb * rb
+                dv = jnp.einsum("bij,bijc,bijd->bicd", alpha, ca, u) \
+                    + jnp.einsum("bij,bijc,bjcd->bicd", alpha, cb, v)
+                v = v + dv
+            if quant_vectors:
+                v = _quant_vectors(v, cfg, codebook, mddq_kernel)
 
-        # equivariant messages: invariant coefficients x geometric directions
-        ca = _dense(xn, qparams[f"{L}/wa"], use_kernels)[:, None] \
-            * (rbf @ qparams[f"{L}/rbf_a"])                  # (B, n, n, Fv)
-        cb = _dense(xn, qparams[f"{L}/wb"], use_kernels)[:, None] \
-            * (rbf @ qparams[f"{L}/rbf_b"])
-        dv = jnp.einsum("bij,bijc,bijd->bicd", alpha, ca, u) \
-            + jnp.einsum("bij,bijc,bjcd->bicd", alpha, cb, v)
-        v = v + dv
-        if quant_vectors:
-            v = _quant_vectors(v, cfg, codebook, mddq_kernel)
+            with jax.named_scope("vnorm_feedback"):
+                x = x + _dense(jax.nn.silu(_vnorm(v)),
+                               qparams[f"{L}/w_vnorm"], use_kernels)
 
-        x = x + _dense(jax.nn.silu(_vnorm(v)), qparams[f"{L}/w_vnorm"],
-                       use_kernels)
-
-    feats = jnp.concatenate([x, _vnorm(v)], axis=-1)
-    e_hid = jax.nn.silu(_dense(feats, qparams["ro_w1"], use_kernels))
-    e_atom = _dense(e_hid, qparams["ro_w2"], use_kernels)[..., 0]  # (B, n)
-    return jnp.sum(e_atom * mask, axis=-1)                   # (B,)
+    with jax.named_scope("readout"):
+        feats = jnp.concatenate([x, _vnorm(v)], axis=-1)
+        e_hid = jax.nn.silu(_dense(feats, qparams["ro_w1"], use_kernels))
+        e_atom = _dense(e_hid, qparams["ro_w2"], use_kernels)[..., 0]
+        return jnp.sum(e_atom * mask, axis=-1)               # (B,)
 
 
 def batched_energy_and_forces(qparams, cfg, species, coords, mask,
@@ -209,15 +242,16 @@ def sparse_energy(qparams: QuantizedParams, cfg: So3kratesConfig,
     # edge geometry from gathered coordinates: the energy stays a function
     # of coords, so forces flow through the gathers; masked slots are
     # self-loops -> d ~ 0, and every use below is edge_mask-gated
-    coords_f = coords.reshape(N, 3)
-    rij = ops.edge_gather(coords_f, senders, n) \
-        - ops.edge_gather(coords_f, receivers, n)            # (E, 3) r_j-r_i
-    d2 = jnp.sum(rij ** 2, -1)
-    if refine_cutoff:
-        edge_mask = edge_mask & (d2 < cfg.cutoff * cfg.cutoff)
-    d = jnp.sqrt(d2 + 1e-12)
-    u = rij / d[..., None]                                   # (E, 3)
-    rbf_e = _rbf(d, cfg) * edge_mask[..., None]              # (E, K)
+    with jax.named_scope("geometry"):
+        coords_f = coords.reshape(N, 3)
+        rij = ops.edge_gather(coords_f, senders, n) \
+            - ops.edge_gather(coords_f, receivers, n)        # (E, 3) rj-ri
+        d2 = jnp.sum(rij ** 2, -1)
+        if refine_cutoff:
+            edge_mask = edge_mask & (d2 < cfg.cutoff * cfg.cutoff)
+        d = jnp.sqrt(d2 + 1e-12)
+        u = rij / d[..., None]                               # (E, 3)
+        rbf_e = _rbf(d, cfg) * edge_mask[..., None]          # (E, K)
 
     mask_f = mask.reshape(N)
     x = qparams["embed"][species.reshape(N)] * mask_f[:, None]   # (N, F)
@@ -225,58 +259,71 @@ def sparse_energy(qparams: QuantizedParams, cfg: So3kratesConfig,
 
     for i in range(cfg.n_layers):
         L = f"layer{i}"
-        xn = _layernorm(x, qparams[f"{L}/ln_g"], qparams[f"{L}/ln_b"])
+        with jax.named_scope(L):
+            # fused trunk projection (q | k | msg | a | b, see
+            # _trunk_matmul)
+            with jax.named_scope("trunk"):
+                xn = _layernorm(x, qparams[f"{L}/ln_g"],
+                                qparams[f"{L}/ln_b"])
+                trunk = _trunk_matmul(qparams, L, xn, mm)    # (N, 3F+2Fv)
+                q, k = trunk[:, :F], trunk[:, F:2 * F]
+            with jax.named_scope("attention"):
+                if cfg.robust_attention:
+                    q_s = cfg.tau * l2_normalize(q)
+                    k_s = l2_normalize(k)
+                else:
+                    q_s = q / jnp.sqrt(q.shape[-1])
+                    k_s = k
 
-        # fused trunk projection (q | k | msg | a | b, see _trunk_matmul)
-        trunk = _trunk_matmul(qparams, L, xn, mm)            # (N, 3F+2Fv)
-        q, k = trunk[:, :F], trunk[:, F:2 * F]
-        if cfg.robust_attention:
-            q_s = cfg.tau * l2_normalize(q)
-            k_s = l2_normalize(k)
-        else:
-            q_s = q / jnp.sqrt(q.shape[-1])
-            k_s = k
+            # fused radial gemm: bias | scalar gate | a-gate | b-gate ride
+            # one (E, K) @ (K, 1+F+2Fv) product (exact column split)
+            with jax.named_scope("radial"):
+                rg = rbf_e @ jnp.concatenate(
+                    [qparams[f"{L}/rbf_bias"], qparams[f"{L}/rbf_m"],
+                     qparams[f"{L}/rbf_a"], qparams[f"{L}/rbf_b"]], axis=1)
+                bias_e = rg[:, 0]                            # (E,)
+                gate_e = rg[:, 1:1 + F]                      # (E, F)
 
-        # fused radial gemm: bias | scalar gate | a-gate | b-gate ride
-        # one (E, K) @ (K, 1+F+2Fv) product (exact column split)
-        rg = rbf_e @ jnp.concatenate(
-            [qparams[f"{L}/rbf_bias"], qparams[f"{L}/rbf_m"],
-             qparams[f"{L}/rbf_a"], qparams[f"{L}/rbf_b"]], axis=1)
-        bias_e = rg[:, 0]                                    # (E,)
-        gate_e = rg[:, 1:1 + F]                              # (E, F)
+            # fused sender gather: scalar messages, both coefficient
+            # projections, and the vector features come off one (E, .)
+            # gather (ops.edge_gather: its VJP is a blocked matmul, not a
+            # scatter)
+            with jax.named_scope("messages"):
+                sf = ops.edge_gather(
+                    jnp.concatenate([trunk[:, 2 * F:], v.reshape(N, Fv * 3)],
+                                    axis=1), senders, n)
+                msg_e = sf[:, :F]                            # (E, F)
+                ca_e = sf[:, F:F + Fv] * rg[:, 1 + F:1 + F + Fv]  # (E, Fv)
+                cb_e = sf[:, F + Fv:F + 2 * Fv] * rg[:, 1 + F + Fv:]
+                # per-edge values for ONE fused softmax-scatter: scalar
+                # messages and both equivariant message terms share alpha
+                vec_e = ca_e[..., None] * u[:, None, :] \
+                    + cb_e[..., None] * sf[:, F + 2 * Fv:].reshape(-1, Fv, 3)
+                vals = jnp.concatenate(
+                    [gate_e * msg_e, vec_e.reshape(-1, Fv * 3)], axis=1)
 
-        # fused sender gather: scalar messages, both coefficient
-        # projections, and the vector features come off one (E, .) gather
-        # (ops.edge_gather: its VJP is a blocked matmul, not a scatter)
-        sf = ops.edge_gather(
-            jnp.concatenate([trunk[:, 2 * F:], v.reshape(N, Fv * 3)],
-                            axis=1), senders, n)
-        msg_e = sf[:, :F]                                    # (E, F)
-        ca_e = sf[:, F:F + Fv] * rg[:, 1 + F:1 + F + Fv]     # (E, Fv)
-        cb_e = sf[:, F + Fv:F + 2 * Fv] * rg[:, 1 + F + Fv:]
-        # per-edge values for ONE fused softmax-scatter: scalar messages
-        # and both equivariant message terms share the same alpha
-        vec_e = ca_e[..., None] * u[:, None, :] \
-            + cb_e[..., None] * sf[:, F + 2 * Fv:].reshape(-1, Fv, 3)
-        vals = jnp.concatenate(
-            [gate_e * msg_e, vec_e.reshape(-1, Fv * 3)], axis=1)
+            with jax.named_scope("attention"):
+                out = ops.edge_softmax(q_s, k_s, bias_e, vals, senders,
+                                       receivers, edge_mask, cap=n,
+                                       use_kernel=edge_kernel)
+            with jax.named_scope("messages"):
+                x = x + out[:, :F]
+            with jax.named_scope("update"):
+                h = jax.nn.silu(mm(x, qparams[f"{L}/w_upd1"]))
+                x = x + mm(h, qparams[f"{L}/w_upd2"])
+            with jax.named_scope("messages"):
+                v = v + out[:, F:].reshape(N, Fv, 3)
+            if quant_vectors:
+                v = _quant_vectors(v, cfg, codebook, mddq_kernel)
 
-        out = ops.edge_softmax(q_s, k_s, bias_e, vals, senders, receivers,
-                               edge_mask, cap=n, use_kernel=edge_kernel)
-        x = x + out[:, :F]
-        h = jax.nn.silu(mm(x, qparams[f"{L}/w_upd1"]))
-        x = x + mm(h, qparams[f"{L}/w_upd2"])
+            with jax.named_scope("vnorm_feedback"):
+                x = x + mm(jax.nn.silu(_vnorm(v)), qparams[f"{L}/w_vnorm"])
 
-        v = v + out[:, F:].reshape(N, Fv, 3)
-        if quant_vectors:
-            v = _quant_vectors(v, cfg, codebook, mddq_kernel)
-
-        x = x + mm(jax.nn.silu(_vnorm(v)), qparams[f"{L}/w_vnorm"])
-
-    feats = jnp.concatenate([x, _vnorm(v)], axis=-1)
-    e_hid = jax.nn.silu(mm(feats, qparams["ro_w1"]))
-    e_atom = mm(e_hid, qparams["ro_w2"])[:, 0]               # (N,)
-    return jnp.sum(e_atom.reshape(B, n) * mask, axis=-1)     # (B,)
+    with jax.named_scope("readout"):
+        feats = jnp.concatenate([x, _vnorm(v)], axis=-1)
+        e_hid = jax.nn.silu(mm(feats, qparams["ro_w1"]))
+        e_atom = mm(e_hid, qparams["ro_w2"])[:, 0]           # (N,)
+        return jnp.sum(e_atom.reshape(B, n) * mask, axis=-1)  # (B,)
 
 
 def sparse_energy_and_forces(qparams, cfg, species, coords, mask,
