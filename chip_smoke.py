@@ -180,6 +180,13 @@ def _serve_phase(cfg, mode: str, graphs):
              f"{len(engine.warmup_report)} programs")
         handles = [s.submit(g) for g in graphs]
         results = [h.result(timeout=600) for h in handles]
+    from repro.obs import REGISTRY
+    snaps = {p: REGISTRY.counter("mddq_snap_programs_total", mode=mode,
+                                 path=p).value
+             for p in ("closed_form", "scan")}
+    _log(f"  mddq_snap_programs_total {snaps}")
+    _require(snaps["scan"] == 0 and snaps["closed_form"] > 0,
+             "every served program snaps in closed form")
     stats = engine.stats_snapshot()
     _log(f"  dispatch_stats {stats}; paths "
          f"{[(r.n_atoms, r.path) for r in results]}")
@@ -223,48 +230,66 @@ def _serve_phase(cfg, mode: str, graphs):
 MDDQ_SHAPES = ((8, 16), (2, 64), (128,), (256,), (MD_REPLICAS * MD_ATOMS,))
 
 
+# random directions beyond MDDQ_SHAPES for the served snap
+MDDQ_RANDOM = 10 ** 6
+
+
 def _mddq_phase(cfg) -> None:
     """Share of MDDQ direction codes that differ from a float64 argmax
-    over the same random unit vectors, for ``core.codebook.nearest_code``
-    (what the forwards snap with) at each of ``MDDQ_SHAPES``, and for a
-    default-precision einsum over all of them (what the snap was before
+    over the same random unit vectors: for the served snap
+    (``core.mddq.mddq_encode``, closed form for the Fibonacci codebook)
+    at each of ``MDDQ_SHAPES`` and over ``MDDQ_RANDOM`` more directions,
+    for ``core.codebook.nearest_code`` (the scan) at each shape, and for
+    a default-precision einsum over the shapes (what the snap was before
     it took elementwise scores). A difference counts as a tie when the
     two codewords' float64 scores are within 1e-6."""
     import jax
     import jax.numpy as jnp
-    from repro.core import make_codebook, nearest_code
+    from repro.core import make_codebook, mddq_encode, nearest_code, snap_path
+    mcfg = cfg.mddq()
     cb = make_codebook(cfg.dir_bits)
     cb64 = np.asarray(cb, np.float64)
     rng = np.random.default_rng(SEED)
     us = []
-    for lead in MDDQ_SHAPES:
-        v = rng.normal(size=lead + (cfg.vec_feat, 3))
+    for shape in [lead + (cfg.vec_feat,) for lead in MDDQ_SHAPES] \
+            + [(MDDQ_RANDOM,)]:
+        v = rng.normal(size=shape + (3,))
         us.append((v / np.linalg.norm(v, axis=-1, keepdims=True))
                   .astype(np.float32))
     flat = np.concatenate([u.reshape(-1, 3) for u in us]).astype(np.float64)
-    exact = np.concatenate([np.argmax(flat[i:i + 256] @ cb64.T, axis=1)
-                            for i in range(0, len(flat), 256)])
+    exact = np.concatenate([np.argmax(flat[i:i + 512] @ cb64.T, axis=1)
+                            for i in range(0, len(flat), 512)])
+    n_shapes = sum(u.size // 3 for u in us[:-1])
 
-    def beyond_ties(idx):
-        diff = idx != exact
-        gap = np.abs(np.sum(flat[diff] * (cb64[idx[diff]]
-                                          - cb64[exact[diff]]), axis=1))
+    def beyond_ties(idx, ref):
+        diff = idx != ref
+        f = flat[:len(ref)]
+        gap = np.abs(np.sum(f[diff] * (cb64[idx[diff]] - cb64[ref[diff]]),
+                            axis=1))
         return diff.sum(), int((gap > 1e-6).sum())
 
     served = np.concatenate([
+        np.asarray(jax.jit(lambda u_: mddq_encode(u_, mcfg, cb)[0])(
+            jnp.asarray(u))).reshape(-1) for u in us])
+    scan = np.concatenate([
         np.asarray(jax.jit(nearest_code)(jnp.asarray(u), cb)).reshape(-1)
-        for u in us])
+        for u in us[:-1]])
     default = np.asarray(jax.jit(lambda u_: jax.lax.map(
         lambda x: jnp.argmax(jnp.einsum("d,nd->n", x, cb)), u_,
-        batch_size=128))(jnp.asarray(flat, jnp.float32)))
-    for name, idx in (("nearest_code", served),
-                      ("default-precision einsum", default)):
-        n_diff, n_beyond = beyond_ties(idx)
+        batch_size=128))(jnp.asarray(flat[:n_shapes], jnp.float32)))
+    for name, idx, what in (
+            (f"served snap, {snap_path(mcfg)}", served,
+             f"shapes {MDDQ_SHAPES} x ({cfg.vec_feat}, 3) and "
+             f"{MDDQ_RANDOM} random"),
+            ("nearest_code", scan, f"shapes {MDDQ_SHAPES}"),
+            ("default-precision einsum", default, f"shapes {MDDQ_SHAPES}")):
+        n_diff, n_beyond = beyond_ties(idx, exact[:len(idx)])
         _log(f"  MDDQ codes differing from the float64 argmax ({name}): "
-             f"{n_diff / len(idx):.4%} ({n_beyond / len(idx):.4%} beyond "
-             f"ties) of {len(idx)} over shapes {MDDQ_SHAPES} x "
-             f"({cfg.vec_feat}, 3)")
-    _require(beyond_ties(served)[1] == 0,
+             f"{n_diff / len(idx):.4%} ({n_diff}; {n_beyond} beyond ties) "
+             f"of {len(idx)} over {what}")
+    _require(beyond_ties(served, exact)[1] == 0,
+             "the served snap finds the nearest codeword everywhere")
+    _require(beyond_ties(scan, exact[:n_shapes])[1] == 0,
              "nearest_code snaps to the nearest codeword at every shape")
 
 

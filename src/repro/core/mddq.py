@@ -5,7 +5,9 @@ Q(v) = Q_m(||v||) * Q_d(v / ||v||)
 * Q_m: scalar quantizer on R_+ — either symmetric-linear (shared scale) or
   log-domain (default; magnitudes are Chi-distributed, log grid keeps relative
   error uniform).
-* Q_d: nearest-codeword lookup in a spherical codebook C subset S^2.
+* Q_d: nearest-codeword lookup in a spherical codebook C subset S^2: in
+  closed form for the Fibonacci codebook, by a scan over every codeword
+  for the octahedral one (``snap_path``); the codes are the same.
 
 Both a *real* path (integer codes, for storage/serving) and a *fake-quant*
 path (quantize-dequantize with Geometric STE, for QAT) are provided.
@@ -18,7 +20,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .codebook import make_codebook, nearest_code
+from .codebook import fibonacci_snap, make_codebook, nearest_code
 from .quantizers import (
     abs_max_scale,
     fake_quant_ste,
@@ -27,7 +29,8 @@ from .quantizers import (
 )
 from .ste import geometric_ste_direction, identity_ste
 
-__all__ = ["MDDQConfig", "mddq_fake_quant", "mddq_encode", "mddq_decode"]
+__all__ = ["MDDQConfig", "mddq_fake_quant", "mddq_encode", "mddq_decode",
+           "snap_path"]
 
 _EPS = 1e-12
 
@@ -44,6 +47,26 @@ class MDDQConfig:
 
     def codebook(self) -> jnp.ndarray:
         return make_codebook(self.direction_bits, self.codebook_kind)
+
+
+def snap_path(cfg: MDDQConfig) -> str:
+    """How Q_d finds the nearest codeword for ``cfg``: ``"closed_form"``
+    (``fibonacci_snap``, 16 candidates per vector) for the Fibonacci
+    codebook, ``"scan"`` (``nearest_code``, every codeword) otherwise.
+    Both give the same codes."""
+    return "closed_form" if cfg.codebook_kind == "fibonacci" else "scan"
+
+
+def _snap(u: jnp.ndarray, cfg: MDDQConfig, codebook: jnp.ndarray):
+    """Q_d: (codes (...,), codewords (..., 3)) of unit vectors u."""
+    if snap_path(cfg) == "scan":
+        idx = nearest_code(u, codebook)
+        return idx, codebook[idx]
+    if codebook.shape[0] != 2 ** cfg.direction_bits:
+        raise ValueError(
+            f"a {codebook.shape[0]}-word codebook for a Fibonacci codebook "
+            f"of {cfg.direction_bits} bits")
+    return fibonacci_snap(u, codebook)
 
 
 def _split(v: jnp.ndarray):
@@ -68,7 +91,7 @@ def mddq_fake_quant(v: jnp.ndarray, cfg: MDDQConfig,
     m, u = _split(v)
 
     # -- direction: snap to nearest codeword (non-differentiable) + STE
-    q_dir = codebook[nearest_code(jax.lax.stop_gradient(u), codebook)]
+    _, q_dir = _snap(jax.lax.stop_gradient(u), cfg, codebook)
     ste = geometric_ste_direction if cfg.geometric_ste else identity_ste
     u_hat = ste(u, q_dir)
 
@@ -99,7 +122,7 @@ def mddq_encode(v: jnp.ndarray, cfg: MDDQConfig,
     if codebook is None:
         codebook = cfg.codebook()
     m, u = _split(v)
-    dir_idx = nearest_code(u, codebook)
+    dir_idx, _ = _snap(u, cfg, codebook)
     if cfg.magnitude_domain == "log":
         mag = quantize_log_magnitude(m[..., 0], cfg.magnitude_bits,
                                      cfg.m_min, cfg.m_max)

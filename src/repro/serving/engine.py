@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import make_codebook
+from repro.core import make_codebook, snap_path
 from repro.core.lee import random_rotation, random_rotations
 from repro.guardrails import (Flag, GuardrailConfig, GuardrailViolation,
                               check_result)
@@ -189,6 +189,13 @@ class QuantizedEngine:
                           if quant_vec else None)
         self._buckets = serve.buckets()
         use_kernels = serve.mode != "fp32"
+        # the direction snap each forward traces (the Pallas encode
+        # kernel scans every codeword); counted per compiled shape in
+        # mddq_snap_programs_total, so a run shows which one it served
+        self._m_snap = (REGISTRY.counter(
+            "mddq_snap_programs_total", mode=serve.mode,
+            path="scan" if serve.mddq_kernel
+            else snap_path(model_cfg.mddq())) if quant_vec else None)
 
         def _fwd_dense(species, coords, mask):
             return batched_energy_and_forces(
@@ -370,14 +377,21 @@ class QuantizedEngine:
                                                     mask, el))
         return time.monotonic() - t0
 
+    def _compiling(self, key) -> None:
+        """Note a shape class the forwards run; the first run of one
+        compiles a program."""
+        if key not in self.compiled_shapes:
+            self.compiled_shapes.add(key)
+            if self._m_snap is not None:
+                self._m_snap.inc()
+
     def _run_dense(self, species, coords, mask):
-        self.compiled_shapes.add(species.shape)
+        self._compiling(species.shape)
         return self._forward_dense(self._put(species), self._put(coords),
                                    self._put(mask))
 
     def _run_sparse(self, species, coords, mask, el):
-        self.compiled_shapes.add(("sparse",) + species.shape
-                                 + (el.edge_capacity,))
+        self._compiling(("sparse",) + species.shape + (el.edge_capacity,))
         return self._forward_sparse(
             self._put(species), self._put(coords), self._put(mask),
             self._put(el.senders), self._put(el.receivers),

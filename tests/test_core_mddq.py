@@ -9,6 +9,7 @@ from helpers.equivariance import assert_rotation_equivariant_bounded
 from repro.core import (
     MDDQConfig,
     covering_radius,
+    fibonacci_snap,
     fibonacci_sphere,
     geometric_ste_direction,
     lee,
@@ -18,13 +19,16 @@ from repro.core import (
     mddq_encode,
     mddq_fake_quant,
     nearest_code,
+    nearest_fibonacci_code,
     octahedral_sphere,
     quantize_direction,
     random_rotation,
     random_rotations,
     robust_attention_weights,
     cosine_attention_logits,
+    snap_path,
 )
+from repro.core import mddq as mddq_mod
 
 
 def _rand_vectors(key, shape):
@@ -109,6 +113,136 @@ class TestMDDQ:
             lambda x: quantize_direction(jnp.asarray(x), cb), u,
             bound=2 * 2 * np.sin(delta / 2) + 1e-5,
             R=np.asarray(random_rotation(k2), np.float32))
+
+
+def _unit(v):
+    v = np.asarray(v, np.float64)
+    return (v / np.linalg.norm(v, axis=-1, keepdims=True)).astype(np.float32)
+
+
+def _snap_vectors(family: str, bits: int, seed: int = 0) -> np.ndarray:
+    """Unit vectors where a closed-form snap can go wrong: anywhere, at
+    the poles, on the equator, on the azimuth's +-pi seam, on codewords,
+    and half-way between consecutive codewords. 2,000 of each (20,000
+    random ones at 16 bits), so one compile serves every family."""
+    n = 2 ** bits
+    rng = np.random.default_rng(seed)
+    cb = np.asarray(make_codebook(bits), np.float64)
+    m = 2000
+    if family == "random":
+        return _unit(rng.normal(size=(20000 if bits == 16 else m, 3)))
+    if family == "codewords":
+        return cb[rng.choice(n, m, replace=n < m)].astype(np.float32)
+    if family == "midpoints":
+        j = rng.choice(n - 1, m, replace=n - 1 < m)
+        return _unit(cb[j] + cb[j + 1])
+    if family == "poles":
+        z = rng.choice([-1.0, 1.0], m) * (1.0 - rng.uniform(0.0, 4.0 / n, m))
+        az = rng.uniform(-np.pi, np.pi, m)
+    elif family == "equator":
+        z = rng.normal(0.0, 1e-4, m)
+        az = rng.uniform(-np.pi, np.pi, m)
+    else:                                   # the seam, from either side
+        z = rng.uniform(-1.0, 1.0, m)
+        az = np.pi + rng.normal(0.0, 1e-6, m)
+    r = np.sqrt(np.maximum(1.0 - z * z, 0.0))
+    return _unit(np.stack([r * np.cos(az), r * np.sin(az), z], -1))
+
+
+class TestClosedFormSnap:
+    """``nearest_fibonacci_code`` returns the scan's codes, ties included."""
+
+    @pytest.mark.parametrize("family", ["random", "poles", "equator", "seam",
+                                        "codewords", "midpoints"])
+    @pytest.mark.parametrize("bits", [4, 6, 8, 10, 12, 16])
+    def test_codes_equal_the_scan(self, bits, family):
+        cb = make_codebook(bits)
+        u = jnp.asarray(_snap_vectors(family, bits))
+        want = np.asarray(jax.jit(nearest_code)(u, cb))
+        got, rows = map(np.asarray, fibonacci_snap(u, cb))
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(rows, np.asarray(cb)[want])
+        if family == "codewords":       # each codeword is its own code
+            np.testing.assert_array_equal(
+                np.asarray(cb)[got], np.asarray(u))
+
+    @pytest.mark.parametrize("bits", [8, 16])
+    def test_zero_vectors(self, bits):
+        cb = make_codebook(bits)
+        idx = np.asarray(nearest_fibonacci_code(jnp.zeros((5, 3)), cb))
+        assert ((idx >= 0) & (idx < 2 ** bits)).all()
+        cfg = MDDQConfig(direction_bits=bits)
+        v = jnp.zeros((4, 3)).at[1].set(jnp.array([0.3, -1.0, 2.0]))
+        out, grad = jax.jit(jax.value_and_grad(
+            lambda v_: jnp.sum(mddq_fake_quant(v_, cfg, cb) ** 2)))(v)
+        assert np.isfinite(np.asarray(out)) and np.isfinite(grad).all()
+        q = np.asarray(jax.jit(lambda v_: mddq_fake_quant(v_, cfg, cb))(v))
+        np.testing.assert_array_equal(q[[0, 2, 3]], 0.0)
+        assert np.linalg.norm(q[1]) > 0
+
+    def test_codes_do_not_depend_on_batch_shape(self):
+        cb = make_codebook(16)
+        u = jnp.asarray(_unit(np.random.default_rng(3).normal(
+            size=(8, 16, 16, 3))))
+        batched = np.asarray(nearest_fibonacci_code(u, cb))
+        flat = np.asarray(nearest_fibonacci_code(u.reshape(-1, 3), cb))
+        assert batched.shape == (8, 16, 16)
+        np.testing.assert_array_equal(batched.reshape(-1), flat)
+
+    @pytest.mark.parametrize("bits", [8, 16])
+    def test_fake_quant_bit_identical_to_the_scan(self, bits, monkeypatch):
+        cfg = MDDQConfig(direction_bits=bits)
+        cb = make_codebook(bits)
+        v = jax.random.normal(jax.random.PRNGKey(bits), (64, 16, 3)) * 2.0
+        v = v.at[0, :4].set(0.0)
+        w = jnp.arange(v.size, dtype=jnp.float32).reshape(v.shape) / v.size
+
+        def out_and_grad():     # op by op, so both run the same kernels
+            return jax.value_and_grad(
+                lambda v_: jnp.sum(w * mddq_fake_quant(v_, cfg, cb)))(v), \
+                mddq_fake_quant(v, cfg, cb)
+
+        (l_cf, g_cf), q_cf = out_and_grad()
+        monkeypatch.setattr(mddq_mod, "fibonacci_snap",
+                            lambda u, c: (nearest_code(u, c),
+                                          c[nearest_code(u, c)]))
+        (l_sc, g_sc), q_sc = out_and_grad()
+        np.testing.assert_array_equal(np.asarray(q_cf), np.asarray(q_sc))
+        np.testing.assert_array_equal(np.asarray(g_cf), np.asarray(g_sc))
+        assert float(l_cf) == float(l_sc)
+
+    @pytest.mark.parametrize("kind,path", [("fibonacci", "closed_form"),
+                                           ("octahedral", "scan")])
+    def test_snap_path_follows_the_codebook_kind(self, kind, path):
+        cfg = MDDQConfig(direction_bits=8, codebook_kind=kind)
+        assert snap_path(cfg) == path
+        v = jax.random.normal(jax.random.PRNGKey(4), (32, 3))
+        idx, _ = mddq_encode(v, cfg)
+        want = nearest_code(v / jnp.linalg.norm(v, axis=-1, keepdims=True),
+                            cfg.codebook())
+        np.testing.assert_array_equal(np.asarray(idx), np.asarray(want))
+
+    def test_wrong_codebook_size_is_refused(self):
+        cfg = MDDQConfig(direction_bits=8)
+        with pytest.raises(ValueError, match="8 bits"):
+            mddq_fake_quant(jnp.ones((2, 3)), cfg, make_codebook(6))
+
+    def test_engine_counts_closed_form_programs(self):
+        from repro.models import so3krates as so3
+        from repro.obs import REGISTRY
+        from repro.serving import QuantizedEngine, ServeConfig
+        serve = ServeConfig(mode="w8a8", bucket_sizes=(8,), max_batch=1)
+        count = {p: REGISTRY.counter("mddq_snap_programs_total",
+                                     mode="w8a8", path=p)
+                 for p in ("closed_form", "scan")}
+        before = {p: c.value for p, c in count.items()}
+        engine = QuantizedEngine.from_config(
+            so3.So3kratesConfig(feat=16, vec_feat=4, n_layers=1, n_rbf=4),
+            serve=serve)
+        engine.warmup()
+        assert count["closed_form"].value - before["closed_form"] \
+            == len(engine.compiled_shapes) > 0
+        assert count["scan"].value == before["scan"]
 
 
 class TestGeometricSTE:

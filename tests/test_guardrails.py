@@ -412,10 +412,12 @@ class TestCircuitBreaker:
                 assert [f.reason for f in r.flags] == ["force_outlier"]
                 delivered += 1
             assert delivered >= 4         # enough to arm the breaker
+            # the trip is counted before the watchdog's quarantine has
+            # cold-restarted the replica: wait for the restart as well
             deadline = time.monotonic() + 10.0
             while time.monotonic() < deadline:
                 gr = pool.stats()["guardrails"]
-                if gr["n_breaker_trips"] >= 1:
+                if gr["n_breaker_trips"] >= 1 and gr["n_respawned"] >= 1:
                     break
                 time.sleep(0.05)
             gr = pool.stats()["guardrails"]
